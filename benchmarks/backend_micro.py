@@ -42,7 +42,7 @@ import numpy as np
 from benchmarks.common import emit
 from repro.core.backend_jax import (
     JaxBackend,
-    SLAB_BYTES,
+    SLAB_SHAPE,
     nbytes_of,
     synth_payload,
 )
@@ -78,7 +78,7 @@ def _per_transfer_arm(be: JaxBackend, src_idx: np.ndarray,
     src = be.store_for("host").slabs
     dst = be.store_for("gpu1")
     t0 = time.perf_counter()
-    staging = np.empty((n, SLAB_BYTES), np.uint8)    # per-transfer alloc
+    staging = np.empty((n, *SLAB_SHAPE), np.uint8)    # per-transfer alloc
     for i in range(n):
         staging[i] = src[src_idx[i]]                 # faults fresh pages
         up = jnp.asarray(staging[i:i + 1])
@@ -212,13 +212,9 @@ def staging_micro(size_mb: float = 96.0) -> dict:
 def pallas_micro(size_mb: float = 8.0) -> dict:
     """Both kernel arms produce identical bytes on a small transfer
     (pallas interpret mode is the slow-but-faithful arm on CPU)."""
-    from repro.kernels.chunked_copy import HAS_PALLAS_TPU
     eng = _engine()
-    out = {"has_pallas_tpu": bool(HAS_PALLAS_TPU)}
+    out = {}
     for use_pallas in (False, True):
-        if use_pallas and not HAS_PALLAS_TPU:
-            out["pallas_ok"] = None       # arm unavailable on this jax
-            continue
         be = JaxBackend(store_mb=64, host_mb=64, use_pallas=use_pallas)
         did = f"pal{int(use_pallas)}"
         plan = eng.compile("h2g", "bench", "host", "gpu1", size_mb,

@@ -4,7 +4,7 @@ The simulator decides *when* a transfer completes; this backend makes
 the same plan move *real* bytes so every simulated band has an
 empirical anchor.  Objects live as 2 MB slab rows inside a real
 ``ElasticPool``-backed slab store per endpoint (``track_slabs`` mode
-hands out concrete row indices into one preallocated ``(n, SLAB_BYTES)``
+hands out concrete row indices into one preallocated ``(n, *SLAB_SHAPE)``
 jax array per device, numpy array per host).  Chunked hops execute
 through the double-buffered pipeline in ``kernels/chunked_copy`` —
 batch k+1's gather dispatches while batch k's scatter drains, with
@@ -39,7 +39,9 @@ from __future__ import annotations
 import time
 import zlib
 from dataclasses import dataclass, field
+from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -54,6 +56,11 @@ from repro.kernels.chunked_copy.pipeline import (
 from repro.kernels.chunked_copy.ops import gather
 
 MB = 2 ** 20
+#: one slab as the pools hold it: 2 MB viewed as (2048, 1024) bytes.  The
+#: TPU's Pallas kernels need a block whose two minor dims tile (8, 128)
+#: or equal the array's, so a whole slab is the block; host arrays share
+#: the shape so every device<->host copy is a plain memcpy.
+SLAB_SHAPE = (SLAB_BYTES // 1024, 1024)
 
 
 def synth_payload(data_id: str, nbytes: int) -> np.ndarray:
@@ -76,6 +83,14 @@ class _Obj:
     rows: tuple            # slab row indices, payload order
 
 
+@partial(jax.jit, static_argnums=1)
+def _grow_pool(slabs, add: int):
+    """``slabs`` with ``add`` zero slabs appended, in one program: the
+    peak is the old pool plus the new one (an eager ``concatenate``
+    would also hold a separate ``add``-slab zeros buffer)."""
+    return jnp.pad(slabs, ((0, add),) + ((0, 0),) * (slabs.ndim - 1))
+
+
 class SlabStore:
     """One endpoint's slab store: a preallocated pool array whose rows
     are handed out by a ``track_slabs`` ElasticPool.  ``device=True``
@@ -84,7 +99,8 @@ class SlabStore:
 
     #: initial physical pool — a device pool memset is ~3 s/GB on a
     #: contended CPU, so stores start small and double on demand up to
-    #: their capacity instead of paying the worst case up front
+    #: their capacity instead of paying the worst case up front; each
+    #: doubling peaks at old + new pool (``_grow_pool``)
     START_MB = 64.0
 
     def __init__(self, name: str, capacity_mb: float, *,
@@ -96,10 +112,10 @@ class SlabStore:
         self.pool = ElasticPool(name, capacity_mb=start,
                                 elastic=False, track_slabs=True)
         if device:
-            self.slabs = jnp.zeros((self.pool.n_slabs, SLAB_BYTES),
+            self.slabs = jnp.zeros((self.pool.n_slabs, *SLAB_SHAPE),
                                    np.uint8)
         else:
-            self.slabs = np.zeros((self.pool.n_slabs, SLAB_BYTES),
+            self.slabs = np.zeros((self.pool.n_slabs, *SLAB_SHAPE),
                                   np.uint8)
         self.objects: dict[str, _Obj] = {}
 
@@ -119,10 +135,9 @@ class SlabStore:
         self.pool.grow(new_cap)
         add = self.pool.n_slabs - self.slabs.shape[0]
         if self.device:
-            self.slabs = jnp.concatenate(
-                [self.slabs, jnp.zeros((add, SLAB_BYTES), np.uint8)])
+            self.slabs = _grow_pool(self.slabs, add)
         else:
-            grown = np.zeros((self.pool.n_slabs, SLAB_BYTES), np.uint8)
+            grown = np.zeros((self.pool.n_slabs, *SLAB_SHAPE), np.uint8)
             grown[:self.slabs.shape[0]] = self.slabs
             self.slabs = grown
         return True
@@ -161,7 +176,7 @@ class SlabStore:
         not the data plane)."""
         obj = self.objects[data_id]
         if self.device:
-            out = np.empty((len(obj.rows), SLAB_BYTES), np.uint8)
+            out = np.empty((len(obj.rows), *SLAB_SHAPE), np.uint8)
             pool_to_host(self.slabs, list(obj.rows), out,
                          batch=len(obj.rows))
         else:
@@ -192,9 +207,10 @@ def _take_rows(pool: np.ndarray, rows, out: np.ndarray):
 
 
 def _chunk_rows(payload: np.ndarray) -> np.ndarray:
-    """Reshape flat bytes to (rows, SLAB_BYTES), zero-padding the tail."""
+    """Reshape flat bytes to (rows, *SLAB_SHAPE), zero-padding the
+    tail."""
     rows = -(-payload.nbytes // SLAB_BYTES)
-    out = np.zeros((rows, SLAB_BYTES), np.uint8)
+    out = np.zeros((rows, *SLAB_SHAPE), np.uint8)
     out.reshape(-1)[:payload.nbytes] = payload
     return out
 
@@ -215,7 +231,7 @@ class HostRing:
         self.host = host
         self.size_mb = size_mb
         self.slots = max(1, int(size_mb // chunk_mb))
-        self.buf = np.zeros((self.slots, SLAB_BYTES), np.uint8)
+        self.buf = np.zeros((self.slots, *SLAB_SHAPE), np.uint8)
         self.buf[:] = 0                 # first-touch every page now
         self.in_flight_mb = 0.0
         self.peak_mb = 0.0

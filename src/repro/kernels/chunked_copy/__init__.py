@@ -1,8 +1,4 @@
-from repro.kernels.chunked_copy.kernel import (
-    HAS_PALLAS_TPU,
-    gather_chunks,
-    scatter_chunks,
-)
+from repro.kernels.chunked_copy.kernel import gather_chunks, scatter_chunks
 from repro.kernels.chunked_copy.ref import gather_chunks_ref, scatter_chunks_ref
 from repro.kernels.chunked_copy.ops import gather, scatter
 from repro.kernels.chunked_copy.pipeline import (

@@ -1,102 +1,85 @@
-"""Chunked pool-slab gather/scatter Pallas TPU kernel.
+"""Chunked pool-slab gather/scatter Pallas TPU kernels.
 
 The data plane of FaaSTube's store: intermediate tensors live as 2 MB
 slabs in the elastic pool; a fetch materializes a logical tensor by
 gathering its slab list (and a store scatters it back).  On GPU this is
-cudaMemcpyAsync per chunk; on TPU we fuse the gather into one kernel whose
-BlockSpec index_map reads the slab table via scalar prefetch — each grid
-step DMAs one slab HBM->VMEM->HBM with no host round-trip.
+cudaMemcpyAsync per chunk; on TPU one kernel walks the slab list, its
+BlockSpec index_map reading the slab table via scalar prefetch — each
+grid step DMAs one slab with no host round-trip.
+
+A pool is ``(N, *slab)``: the leading axis indexes slabs and the block
+is one whole slab with that axis squeezed, so the block's trailing dims
+equal the array's and Mosaic's (8, 128) tiling rule holds for any slab
+shape.  The device stores keep slabs 3-D (``(rows, 1024)`` per slab);
+a flat ``(N, 2 MB)`` pool would put a 1-row block on the sublane axis,
+which the TPU compiler refuses.
+
+``interpret`` has no default: the caller decides (``ops`` derives it
+from the JAX backend, so nothing interprets on a TPU).
 """
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
+import jax.experimental.pallas.tpu as pltpu
 from jax.experimental import pallas as pl
 
-# the TPU-specific pallas namespace moved between jax releases
-# (jax.experimental.pallas.tpu -> jax.experimental.pallas.mosaic); try
-# both so importing the kernels package never hard-fails — callers that
-# need the pallas arm check HAS_PALLAS_TPU (ops.gather/scatter fall back
-# to the ref arm when it is False).  Floor: jax>=0.4.37 (interpret mode
-# on CPU); see requirements-dev.txt and tests/_jaxcompat.py.
-try:
-    import jax.experimental.pallas.tpu as pltpu
-except ImportError:  # pragma: no cover - exercised only on newer jax
-    try:
-        import jax.experimental.pallas.mosaic as pltpu
-    except ImportError:
-        pltpu = None
 
-HAS_PALLAS_TPU = pltpu is not None and hasattr(pltpu, "PrefetchScalarGridSpec")
+def _slab_spec(shape, index_map):
+    """One whole slab of an ``(N, *slab)`` pool, slab axis squeezed."""
+    zeros = (0,) * (len(shape) - 1)
+    return pl.BlockSpec((None, *shape[1:]),
+                        lambda i, idx_ref: (index_map(i, idx_ref), *zeros))
 
 
 def _copy_kernel(idx_ref, src_ref, out_ref):
-    out_ref[0] = src_ref[0]
+    out_ref[...] = src_ref[...]
 
 
-def gather_chunks(src, idx, *, interpret: bool = True):
-    """out[i] = src[idx[i]].  src: (N, C); idx: (M,) int32 -> (M, C)."""
-    if pltpu is None:  # pragma: no cover - guarded by HAS_PALLAS_TPU
-        raise RuntimeError(
-            "pallas TPU namespace unavailable in this jax build; "
-            "use ops.gather(..., use_pallas=False)")
-    N, C = src.shape
+def gather_chunks(src, idx, *, interpret: bool):
+    """out[i] = src[idx[i]].  src: (N, *slab); idx: (M,) int32 ->
+    (M, *slab).  Grid over M: traffic scales with the batch, not the
+    pool."""
     M = idx.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(M,),
-        in_specs=[pl.BlockSpec((1, C), lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, C), lambda i, idx_ref: (i, 0)),
+        in_specs=[_slab_spec(src.shape, lambda i, idx_ref: idx_ref[i])],
+        out_specs=_slab_spec(src.shape, lambda i, idx_ref: i),
     )
     return pl.pallas_call(
         _copy_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, C), src.dtype),
+        out_shape=jax.ShapeDtypeStruct((M, *src.shape[1:]), src.dtype),
         interpret=interpret,
     )(idx, src)
 
 
-def scatter_chunks(dst, src, idx, *, interpret: bool = True):
+def _scatter_kernel(idx_ref, dst_ref, src_ref, out_ref):
+    del dst_ref                 # aliased to out: untouched slabs stay
+    out_ref[...] = src_ref[...]
+
+
+def scatter_chunks(dst, src, idx, *, interpret: bool):
     """dst[idx[i]] = src[i] (non-aliasing slab writes).
 
-    dst: (N, C); src: (M, C); idx: (M,) int32 with unique entries.
-    Implemented as a full-pool pass: grid over N, each step either copies
-    the incoming slab or keeps the existing one (alias-free functional
-    update; on real TPU input_output_aliasing makes this in-place).
+    dst: (N, *slab); src: (M, *slab); idx: (M,) int32 with unique
+    entries.  The output aliases ``dst`` and the grid covers only the M
+    incoming slabs, so slabs not in ``idx`` are never read or written:
+    in place when the caller donates ``dst`` (pipeline._scatter_into),
+    one pool copy by XLA when it does not.
     """
-    if pltpu is None:  # pragma: no cover - guarded by HAS_PALLAS_TPU
-        raise RuntimeError(
-            "pallas TPU namespace unavailable in this jax build; "
-            "use ops.scatter(..., use_pallas=False)")
-    N, C = dst.shape
     M = idx.shape[0]
-    # inverse map: for each dst slab, which src row lands there (-1 = keep)
-    inv = jnp.full((N,), -1, jnp.int32).at[idx].set(jnp.arange(M, dtype=jnp.int32))
-
-    def kernel(inv_ref, dst_ref, src_ref, out_ref):
-        i = pl.program_id(0)
-        take = inv_ref[i] >= 0
-
-        @pl.when(take)
-        def _src():
-            out_ref[0] = src_ref[0]
-
-        @pl.when(jnp.logical_not(take))
-        def _keep():
-            out_ref[0] = dst_ref[0]
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(N,),
-        in_specs=[
-            pl.BlockSpec((1, C), lambda i, inv_ref: (i, 0)),
-            pl.BlockSpec((1, C), lambda i, inv_ref: (jnp.maximum(inv_ref[i], 0), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, C), lambda i, inv_ref: (i, 0)),
+        grid=(M,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  _slab_spec(src.shape, lambda i, idx_ref: i)],
+        out_specs=_slab_spec(dst.shape, lambda i, idx_ref: idx_ref[i]),
     )
     return pl.pallas_call(
-        kernel,
+        _scatter_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, C), dst.dtype),
+        out_shape=jax.ShapeDtypeStruct(dst.shape, dst.dtype),
+        input_output_aliases={1: 0},
         interpret=interpret,
-    )(inv, dst, src)
+    )(idx, dst, src)

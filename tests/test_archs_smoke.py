@@ -15,10 +15,6 @@ SHAPE = ShapeSpec("smoke_train", 32, 2, "train")
 
 @pytest.fixture(scope="module")
 def mesh():
-    from _jaxcompat import MODERN_JAX
-    if not MODERN_JAX:
-        pytest.skip(f"installed jax {jax.__version__} lacks "
-                    "set_mesh/AxisType; model tests require jax>=0.6")
     from repro.launch.mesh import make_smoke_mesh
     return make_smoke_mesh()
 

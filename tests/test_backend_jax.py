@@ -31,7 +31,6 @@ from repro.core.transfer import (
     STORE_FORWARD,
     TransferEngine,
 )
-from repro.kernels.chunked_copy import HAS_PALLAS_TPU
 
 
 def make_engine(topo_fn=dgx_v100, **kw):
@@ -199,8 +198,6 @@ def test_facade_spill_reload_real_bytes():
         tube.backend.read_object("d0", "gpu2"), oracle("d0", 16.0))
 
 
-@pytest.mark.skipif(not HAS_PALLAS_TPU,
-                    reason="pallas TPU namespace unavailable")
 def test_pallas_arm_byte_identical():
     """use_pallas=True (interpret mode on CPU) is interchangeable with
     the jnp reference arm."""

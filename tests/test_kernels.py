@@ -8,8 +8,8 @@ import pytest
 from _hyp import given, settings, st
 
 from repro.kernels.chunked_copy import (
-    HAS_PALLAS_TPU, copy_slabs_pipelined, copy_slabs_sequential,
-    gather_chunks, gather_chunks_ref, scatter_chunks, scatter_chunks_ref)
+    copy_slabs_pipelined, copy_slabs_sequential, gather_chunks,
+    gather_chunks_ref, scatter_chunks, scatter_chunks_ref)
 from repro.kernels.chunked_copy.ops import gather, scatter
 from repro.kernels.flash_attention import attention_ref, flash_attention
 from repro.kernels.paged_attention import paged_attention, paged_attention_ref
@@ -111,17 +111,17 @@ def test_chunked_gather_scatter_property(n, m, c, dtype):
                                 jnp.float32).astype(dtype)
     idx = jax.random.permutation(jax.random.key(2), n)[:m].astype(jnp.int32)
     np.testing.assert_array_equal(
-        np.asarray(gather_chunks(src, idx)),
+        np.asarray(gather_chunks(src, idx, interpret=True)),
         np.asarray(gather_chunks_ref(src, idx)))
     dst = jnp.zeros((n, c), dtype)
     np.testing.assert_array_equal(
-        np.asarray(scatter_chunks(dst, new, idx)),
+        np.asarray(scatter_chunks(dst, new, idx, interpret=True)),
         np.asarray(scatter_chunks_ref(dst, new, idx)))
 
 
-# both kernel arms: the pallas interpret kernel and the jnp reference
-# must be interchangeable everywhere the backend flips use_pallas
-PALLAS_ARMS = [False] + ([True] if HAS_PALLAS_TPU else [])
+# both kernel arms: the pallas interpret kernel and the XLA arm must be
+# interchangeable everywhere the backend flips use_pallas
+PALLAS_ARMS = [False, True]
 
 
 @pytest.mark.parametrize("use_pallas", PALLAS_ARMS)

@@ -160,11 +160,6 @@ def test_init_process_determinism():
     """Param init must be byte-identical across processes with different
     PYTHONHASHSEED (multi-host init correctness; regression for the
     hash(name) -> crc32(name) fix)."""
-    from _jaxcompat import MODERN_JAX
-    if not MODERN_JAX:
-        pytest.skip("model-stack test; spawns full init_params "
-                    "subprocesses — requires jax>=0.6 (minutes on the "
-                    "legacy-jax CPU fallback)")
     import subprocess
     import sys
 
