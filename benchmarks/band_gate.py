@@ -38,13 +38,9 @@ SKIP_KEYS = {
     "wall_s", "wall_clock", "total_wall_s", "events_per_sec",
     "chunk_exact_events_per_sec", "coalesce_speedup_x",
     "contended_speedup_x",
-    # real-bytes backend micros (BENCH_backend / BENCH_calibrate):
-    # wall-clock MB/s, fitted bandwidths and error magnitudes move with
-    # the machine; the deterministic shape (chunk counts, boundaries,
-    # peaks, the *_ok flags) stays gated
-    "wall_ms", "speedup_x",
-    "per_transfer_ms", "seq_warm_ms", "pipelined_ms",
-    "per_transfer_mb_s", "seq_warm_mb_s", "pipelined_mb_s",
+    # the real-bytes calibration (BENCH_calibrate): fitted bandwidths
+    # and error magnitudes move with the machine; the deterministic
+    # shape (boundaries, the *_ok flags) stays gated
     "bw_gbps", "lat_ms", "slope_ms_per_mb", "intercept_ms",
     "holdout_err_pct", "median_err_pct",
     "sim_ms", "measured_ms", "sim_vs_real_x",

@@ -53,6 +53,7 @@ from repro.core.migration import (
 from repro.core.pathfinder import PathFinder
 from repro.core.pcie_scheduler import PcieScheduler
 from repro.core.pinned_buffer import CircularPinnedBuffer
+from repro.core.spans import span
 from repro.core.topology import PCIE_PINNED, Topology
 from repro.core.transfer import (
     CUT_THROUGH, STORE_FORWARD, TransferEngine, TransferHandle, host_of,
@@ -356,53 +357,54 @@ class FaaSTube(ChaosMixin, MigrationMixin):
         return value is a lower bound; pass ``on_ready(sim, t)`` to
         observe the true completion-driven ready time.
         """
-        self._pool(device)               # ensure pool + item store exist
-        item = StoredItem(data_id, size_mb, now, now, consumer_pos,
-                          func=func)
-        self.items[device][data_id] = item
-        self._home[data_id] = device
-        if self.backend is not None:
-            # real bytes: materialize the object's payload into the
-            # device's slab store (deterministic synthetic content —
-            # the same oracle the conformance suite regenerates)
-            item.slabs = self.backend.put_object(data_id, device,
-                                                 size_mb=size_mb)
-        rec = DataRecord(data_id, node_of(device), device, size_mb,
-                         "device", -1)
-        self.index.publish(rec)
+        with span("store", data_id=data_id, mb=size_mb, ep=device):
+            self._pool(device)               # ensure pool + item store exist
+            item = StoredItem(data_id, size_mb, now, now, consumer_pos,
+                              func=func)
+            self.items[device][data_id] = item
+            self._home[data_id] = device
+            if self.backend is not None:
+                # real bytes: materialize the object's payload into the
+                # device's slab store (deterministic synthetic content —
+                # the same oracle the conformance suite regenerates)
+                item.slabs = self.backend.put_object(data_id, device,
+                                                     size_mb=size_mb)
+            rec = DataRecord(data_id, node_of(device), device, size_mb,
+                             "device", -1)
+            self.index.publish(rec)
 
-        if not is_device(device):
-            # host-side store: host memory is unbounded, never spills
-            if self.cfg.pool == "none":
-                buf, cost = -1, alloc_ms(size_mb)
-            else:
-                buf, cost = self.pools[device].alloc(func, size_mb, now)
-            self.stats["alloc_ms"] += cost
-            item.held = device
-            rec.buf_id = buf
-            ready = now + cost
-            if on_ready is not None:
-                self.sim.call_at(ready, lambda sim: on_ready(sim, ready))
-            return ready
-
-        def grant(t, buf, cost):
-            if self.items.get(device, {}).get(data_id) is not item:
-                self._unalloc(device, buf, item.size_mb, t)
-                return                   # consumed while waiting for room
-            self.stats["alloc_ms"] += cost
-            item.held = device
-            if buf >= 0:
-                rec.buf_id = buf
-            ready = t + cost
-            if on_ready is not None:
-                if ready > self.sim.now:
-                    self.sim.call_at(ready,
-                                     lambda sim: on_ready(sim, ready))
+            if not is_device(device):
+                # host-side store: host memory is unbounded, never spills
+                if self.cfg.pool == "none":
+                    buf, cost = -1, alloc_ms(size_mb)
                 else:
-                    on_ready(self.sim, ready)
+                    buf, cost = self.pools[device].alloc(func, size_mb, now)
+                self.stats["alloc_ms"] += cost
+                item.held = device
+                rec.buf_id = buf
+                ready = now + cost
+                if on_ready is not None:
+                    self.sim.call_at(ready, lambda sim: on_ready(sim, ready))
+                return ready
 
-        self._reserve(device, func, size_mb, now, grant)
-        return now   # lower bound; true ready time arrives via on_ready
+            def grant(t, buf, cost):
+                if self.items.get(device, {}).get(data_id) is not item:
+                    self._unalloc(device, buf, item.size_mb, t)
+                    return                   # consumed while waiting for room
+                self.stats["alloc_ms"] += cost
+                item.held = device
+                if buf >= 0:
+                    rec.buf_id = buf
+                ready = t + cost
+                if on_ready is not None:
+                    if ready > self.sim.now:
+                        self.sim.call_at(ready,
+                                         lambda sim: on_ready(sim, ready))
+                    else:
+                        on_ready(self.sim, ready)
+
+            self._reserve(device, func, size_mb, now, grant)
+            return now   # lower bound; true ready time arrives via on_ready
 
     def adopt_host_object(self, func: str, data_id: str, size_mb: float,
                           host: str, now: float, *,
@@ -470,103 +472,106 @@ class FaaSTube(ChaosMixin, MigrationMixin):
         :class:`~repro.core.transfer.TransferHandle`; the handle is also
         returned.  None (the default) arms nothing: the event stream
         stays byte-identical to a progress-free run."""
-        if node_of(dst) in self.dead_nodes:
-            if on_error is not None:
-                err = ObjectLost(data_id, node_of(dst),
-                                 "destination node crashed")
+        rec = self.index.global_table.get(data_id)
+        with span("fetch", data_id=data_id,
+                  mb=rec.size_mb if rec is not None else 0.0, ep=dst):
+            if node_of(dst) in self.dead_nodes:
+                if on_error is not None:
+                    err = ObjectLost(data_id, node_of(dst),
+                                     "destination node crashed")
+                    self.sim.call_at(now, lambda sim: on_error(sim, err))
+                return
+            try:
+                rec, lk = self.index.lookup(node_of(dst), data_id)
+            except KeyError:
+                if on_error is None:
+                    raise
+                err = ObjectLost(data_id, "", "not in index")
                 self.sim.call_at(now, lambda sim: on_error(sim, err))
-            return
-        try:
-            rec, lk = self.index.lookup(node_of(dst), data_id)
-        except KeyError:
-            if on_error is None:
-                raise
-            err = ObjectLost(data_id, "", "not in index")
-            self.sim.call_at(now, lambda sim: on_error(sim, err))
-            return
-        if not self.cfg.unified_index:
-            lk += 0.1                     # per-op RPC instead of local pipe
-        t0 = now + lk
-        home = self._home.get(data_id)
-        item = self.items.get(home, {}).get(data_id) \
-            if home is not None else None
-        if item is not None and item.state == RELOADING:
-            # an h2g reload is already in flight: park this fetch; it is
-            # re-dispatched (paying its own move from the landed copy)
-            # when the reload completes, or failed over when the reload
-            # fails and the item is unrecoverable
-            def parked(sim, t, err=None):
-                if err is not None:
-                    if on_error is not None:
-                        on_error(sim, err)
-                    return
-                self.fetch(func, data_id, dst, t, slo_ms=slo_ms,
-                           infer_ms=infer_ms, on_ready=on_ready,
-                           on_error=on_error, on_progress=on_progress)
-            item.waiters.append(parked)
-            return
-        # HOST only: a SPILLING item's device copy is still valid — a
-        # racing fetch coherently reads it through the normal paths below
-        spilled = item is not None and item.state == HOST
-        src = rec.device
-        if item is not None:
-            item.last_access = t0
-        kind = self._movement(src, dst, spilled)
-        if self.cfg.pool == "none" and is_device(dst) and src != dst \
-                and not spilled:
-            # receiver allocates the destination buffer with cudaMalloc;
-            # pooled configs serve it from warm blocks for free (reloads
-            # allocate through the store's capacity machinery instead)
-            c = alloc_ms(rec.size_mb)
-            self.stats["alloc_ms"] += c
-            t0 += c
+                return
+            if not self.cfg.unified_index:
+                lk += 0.1                 # per-op RPC instead of local pipe
+            t0 = now + lk
+            home = self._home.get(data_id)
+            item = self.items.get(home, {}).get(data_id) \
+                if home is not None else None
+            if item is not None and item.state == RELOADING:
+                # an h2g reload is already in flight: park this fetch; it is
+                # re-dispatched (paying its own move from the landed copy)
+                # when the reload completes, or failed over when the reload
+                # fails and the item is unrecoverable
+                def parked(sim, t, err=None):
+                    if err is not None:
+                        if on_error is not None:
+                            on_error(sim, err)
+                        return
+                    self.fetch(func, data_id, dst, t, slo_ms=slo_ms,
+                               infer_ms=infer_ms, on_ready=on_ready,
+                               on_error=on_error, on_progress=on_progress)
+                item.waiters.append(parked)
+                return
+            # HOST only: a SPILLING item's device copy is still valid — a
+            # racing fetch coherently reads it through the normal paths below
+            spilled = item is not None and item.state == HOST
+            src = rec.device
+            if item is not None:
+                item.last_access = t0
+            kind = self._movement(src, dst, spilled)
+            if self.cfg.pool == "none" and is_device(dst) and src != dst \
+                    and not spilled:
+                # receiver allocates the destination buffer with cudaMalloc;
+                # pooled configs serve it from warm blocks for free (reloads
+                # allocate through the store's capacity machinery instead)
+                c = alloc_ms(rec.size_mb)
+                self.stats["alloc_ms"] += c
+                t0 += c
 
-        # foreground-class admission with the caller's SLO context; a
-        # demand reload of spilled data rides this same admission (it
-        # blocks this fetch, so it is foreground work, not migration)
-        if self.sched:
-            self.sched.admit(func, rec.size_mb, slo_ms, infer_ms, t=now)
-
-        def done(sim, tr=None):
+            # foreground-class admission with the caller's SLO context; a
+            # demand reload of spilled data rides this same admission (it
+            # blocks this fetch, so it is foreground work, not migration)
             if self.sched:
-                self.sched.complete(func, t=sim.now)
-            if on_ready:
-                on_ready(sim, sim.now)
-            self._reader_done(data_id, sim)
+                self.sched.admit(func, rec.size_mb, slo_ms, infer_ms, t=now)
 
-        def failed(sim, err):
-            # a failed fetch is not an SLO sample: release the admission
-            # without a completion timestamp, then surface the cause
-            if self.sched:
-                self.sched.complete(func)
-            if on_error is not None:
-                on_error(sim, err)
-            self._reader_done(data_id, sim)
+            def done(sim, tr=None):
+                if self.sched:
+                    self.sched.complete(func, t=sim.now)
+                if on_ready:
+                    on_ready(sim, sim.now)
+                self._reader_done(data_id, sim)
 
-        # in-flight reader refcount: a partial consume issued while any
-        # reader is still landing defers the real release to the last
-        # reader's completion (``_reader_done``)
-        handle = None
-        if on_progress is not None:
-            handle = TransferHandle(rec.size_mb)
-            handle.subscribe(on_progress)
-            self._reader_handles.setdefault(data_id, []).append(handle)
-        self._readers[data_id] = self._readers.get(data_id, 0) + 1
+            def failed(sim, err):
+                # a failed fetch is not an SLO sample: release the admission
+                # without a completion timestamp, then surface the cause
+                if self.sched:
+                    self.sched.complete(func)
+                if on_error is not None:
+                    on_error(sim, err)
+                self._reader_done(data_id, sim)
 
-        if kind == "reload":
-            self._demand_reload(func, item, rec, dst, t0, done, failed,
-                                handle=handle)
+            # in-flight reader refcount: a partial consume issued while any
+            # reader is still landing defers the real release to the last
+            # reader's completion (``_reader_done``)
+            handle = None
+            if on_progress is not None:
+                handle = TransferHandle(rec.size_mb)
+                handle.subscribe(on_progress)
+                self._reader_handles.setdefault(data_id, []).append(handle)
+            self._readers[data_id] = self._readers.get(data_id, 0) + 1
+
+            if kind == "reload":
+                self._demand_reload(func, item, rec, dst, t0, done, failed,
+                                    handle=handle)
+                return handle
+            a, b = src, dst
+            if kind == "h2g" and not src:
+                a = host_of(dst)
+            plan = self.engine.compile(kind, func, a, b, rec.size_mb,
+                                       slo_ms=slo_ms, infer_ms=infer_ms,
+                                       data_id=data_id)
+            self.engine.submit(plan, t0, on_done=done,
+                               on_fail=failed if on_error is not None
+                               else None, handle=handle)
             return handle
-        a, b = src, dst
-        if kind == "h2g" and not src:
-            a = host_of(dst)
-        plan = self.engine.compile(kind, func, a, b, rec.size_mb,
-                                   slo_ms=slo_ms, infer_ms=infer_ms,
-                                   data_id=data_id)
-        self.engine.submit(plan, t0, on_done=done,
-                           on_fail=failed if on_error is not None
-                           else None, handle=handle)
-        return handle
 
     def put(self, func: str, src_dev: str, size_mb: float, now: float, *,
             slo_ms: float = 1e9, infer_ms: float = 0.0, on_done=None,
@@ -613,20 +618,23 @@ class FaaSTube(ChaosMixin, MigrationMixin):
         (``_reader_done``).  Returns the MB the caller may already read:
         the smallest landed prefix across in-flight readers, or the full
         size once nothing is in flight."""
-        if partial and self._readers.get(data_id, 0) > 0:
-            home = self._home.get(data_id, device)
-            it = self.items.get(home, {}).get(data_id)
-            if it is not None:
-                it.set_state(PARTIAL)
-                self._pending_consume[data_id] = device
-                rec = self.index.global_table.get(data_id)
-                if rec is not None:
-                    rec.location = "partial"
-                handles = self._reader_handles.get(data_id)
-                if handles:
-                    return min(h.done_mb for h in handles)
-                return 0.0
-        return self._finish_consume(data_id, device, now)
+        rec = self.index.global_table.get(data_id)
+        with span("consume", data_id=data_id,
+                  mb=rec.size_mb if rec is not None else 0.0, ep=device):
+            if partial and self._readers.get(data_id, 0) > 0:
+                home = self._home.get(data_id, device)
+                it = self.items.get(home, {}).get(data_id)
+                if it is not None:
+                    it.set_state(PARTIAL)
+                    self._pending_consume[data_id] = device
+                    rec = self.index.global_table.get(data_id)
+                    if rec is not None:
+                        rec.location = "partial"
+                    handles = self._reader_handles.get(data_id)
+                    if handles:
+                        return min(h.done_mb for h in handles)
+                    return 0.0
+            return self._finish_consume(data_id, device, now)
 
     def _finish_consume(self, data_id: str, device: str,
                         now: float) -> float:

@@ -45,9 +45,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.elastic_pool import BLOCK_MB, SLAB_BYTES, ElasticPool
 from repro.errors import PoolCapacityError
 from repro.core.linksim import BATCH_CHUNKS
+from repro.core.spans import leaf
 from repro.core.transfer import TransferPlan, host_of, is_device
 from repro.kernels.chunked_copy.pipeline import (
     _scatter_into,
@@ -61,6 +63,11 @@ MB = 2 ** 20
 #: or equal the array's, so a whole slab is the block; host arrays share
 #: the shape so every device<->host copy is a plain memcpy.
 SLAB_SHAPE = (SLAB_BYTES // 1024, 1024)
+#: ``JaxBackend.counters``: bytes through each host-side step — object
+#: writes into device and host stores, the padded copy of a put, host
+#: copies into the ring or staged rows, writes of host destination rows,
+#: uploads and downloads
+COUNTERS = ("put.dev", "put.host", "pad", "stage", "write", "h2d", "d2h")
 
 
 def synth_payload(data_id: str, nbytes: int) -> np.ndarray:
@@ -104,10 +111,12 @@ class SlabStore:
     START_MB = 64.0
 
     def __init__(self, name: str, capacity_mb: float, *,
-                 device: bool = True):
+                 device: bool = True, counters: dict | None = None):
         self.name = name
         self.device = device
         self.capacity_mb = capacity_mb
+        self.counters = dict.fromkeys(COUNTERS, 0) if counters is None \
+            else counters
         start = min(self.START_MB, capacity_mb)
         self.pool = ElasticPool(name, capacity_mb=start,
                                 elastic=False, track_slabs=True)
@@ -132,14 +141,16 @@ class SlabStore:
                       self.capacity_mb)
         if new_cap <= self.pool.capacity_mb:
             return False
-        self.pool.grow(new_cap)
-        add = self.pool.n_slabs - self.slabs.shape[0]
-        if self.device:
-            self.slabs = _grow_pool(self.slabs, add)
-        else:
-            grown = np.zeros((self.pool.n_slabs, *SLAB_SHAPE), np.uint8)
-            grown[:self.slabs.shape[0]] = self.slabs
-            self.slabs = grown
+        with leaf(spans.GROW):
+            self.pool.grow(new_cap)
+            add = self.pool.n_slabs - self.slabs.shape[0]
+            if self.device:
+                self.slabs = _grow_pool(self.slabs, add)
+            else:
+                grown = np.zeros((self.pool.n_slabs, *SLAB_SHAPE),
+                                 np.uint8)
+                grown[:self.slabs.shape[0]] = self.slabs
+                self.slabs = grown
         return True
 
     def alloc(self, data_id: str, nbytes: int) -> _Obj:
@@ -159,16 +170,29 @@ class SlabStore:
 
     def put(self, data_id: str, payload: np.ndarray) -> _Obj:
         """Materialize host bytes into the store (the write path)."""
-        payload = np.ascontiguousarray(payload, dtype=np.uint8).ravel()
-        obj = self.alloc(data_id, payload.nbytes)
-        chunks = _chunk_rows(payload)
-        if self.device:
-            idx = np.asarray(obj.rows, np.int32)
-            self.slabs = _scatter_into(self.slabs, jnp.asarray(chunks),
-                                       idx, use_pallas=False)
-            self.slabs.block_until_ready()
-        else:
-            self.slabs[list(obj.rows)] = chunks
+        c = self.counters
+        with leaf(spans.PUT_DEV if self.device else spans.PUT_HOST):
+            payload = np.ascontiguousarray(payload, dtype=np.uint8).ravel()
+            obj = self.alloc(data_id, payload.nbytes)
+            with leaf(spans.PUT_PAD):
+                chunks = _chunk_rows(payload)
+            c["pad"] += chunks.nbytes
+            if self.device:
+                idx = np.asarray(obj.rows, np.int32)
+                with leaf(spans.PUT_H2D):
+                    up = jnp.asarray(chunks)
+                c["h2d"] += chunks.nbytes
+                with leaf(spans.PUT_SCATTER):
+                    self.slabs = _scatter_into(self.slabs, up, idx,
+                                               use_pallas=False)
+                with leaf(spans.PUT_SYNC):
+                    self.slabs.block_until_ready()
+                c["put.dev"] += payload.nbytes
+            else:
+                with leaf(spans.PUT_WRITE):
+                    self.slabs[list(obj.rows)] = chunks
+                c["write"] += chunks.nbytes
+                c["put.host"] += payload.nbytes
         return obj
 
     def read(self, data_id: str) -> np.ndarray:
@@ -221,10 +245,10 @@ class HostRing:
     reserves ONE trigger-batch window (``min(transfer, batch_mb)``) for
     its lifetime and lands every batch in that same window — bounded
     occupancy is the point; double-buffering lives in the XLA dispatch
-    queue, not in extra ring space.  The first-touch page-fault cost the
-    per-transfer arm pays (benchmarks/backend_micro.py) is exactly what
-    this preallocation amortizes — the CPU analogue of the paper's
-    §6.1 per-transfer cudaHostAlloc vs pre-pinned circular buffer."""
+    queue, not in extra ring space.  The preallocation pays the
+    first-touch page faults once, where a per-transfer buffer would pay
+    them on every transfer — the CPU analogue of the paper's §6.1
+    per-transfer cudaHostAlloc vs pre-pinned circular buffer."""
 
     def __init__(self, host: str, size_mb: float = 40.0,
                  chunk_mb: float = BLOCK_MB):
@@ -312,6 +336,8 @@ class JaxBackend:
         self.stores: dict[str, SlabStore] = {}
         self.rings: dict[str, HostRing] = {}
         self.reports: list[ExecReport] = []
+        #: int bytes per ``COUNTERS`` key, beside the spans that time them
+        self.counters = dict.fromkeys(COUNTERS, 0)
 
     # ------------------------------------------------------------ stores --
     def store_for(self, endpoint: str) -> SlabStore:
@@ -320,7 +346,7 @@ class JaxBackend:
             dev = is_device(endpoint)
             st = SlabStore(endpoint,
                            self.store_mb if dev else self.host_mb,
-                           device=dev)
+                           device=dev, counters=self.counters)
             self.stores[endpoint] = st
         return st
 
@@ -380,7 +406,6 @@ class JaxBackend:
         rep = ExecReport(plan.kind, plan.func, plan.src, plan.dst,
                          plan.size_mb, plan.staging, n_chunks, n_batches,
                          stripes)
-        t0 = time.perf_counter()
 
         def landed(nrows: int, tag: str):
             mb = min(nrows * BLOCK_MB, plan.size_mb)
@@ -390,11 +415,14 @@ class JaxBackend:
                 on_progress(mb)
             rep.hop_trace.append(tag)
 
-        if plan.staging == "store_forward" and len(plan.hops) > 1:
-            self._store_forward(plan, obj, rep, landed)
-        else:
-            self._cut_through(plan, obj, rep, landed)
-        rep.wall_ms = (time.perf_counter() - t0) * 1e3
+        with spans.span("exec." + plan.kind, mb=plan.size_mb,
+                        staging=plan.staging):
+            t0 = time.perf_counter()
+            if plan.staging == "store_forward" and len(plan.hops) > 1:
+                self._store_forward(plan, obj, rep, landed)
+            else:
+                self._cut_through(plan, obj, rep, landed)
+            rep.wall_ms = (time.perf_counter() - t0) * 1e3
         self.reports.append(rep)
         return rep
 
@@ -433,6 +461,7 @@ class JaxBackend:
                 for hk in dict.fromkeys(staged_hosts)}
         rep.peak_staging_mb = max(
             (self.rings[hk].in_flight_mb for hk in wins), default=0.0)
+        c = self.counters
         try:
             for bi, (s, e) in enumerate(self._batches(len(obj.rows))):
                 nb = e - s
@@ -446,56 +475,79 @@ class JaxBackend:
                         order = self._stripe_order(nb, rep.stripes)
                         sidx = np.asarray(obj.rows[s:e], np.int32)[order]
                         didx = np.asarray(dst_rows[s:e], np.int32)[order]
-                        g = gather(src_st.slabs, sidx,
-                                   use_pallas=self.use_pallas)
-                        dst_st.slabs.block_until_ready()
-                        dst_st.slabs = _scatter_into(
-                            dst_st.slabs, g, didx,
-                            use_pallas=self.use_pallas)
+                        with leaf(spans.G2G_GATHER):
+                            g = gather(src_st.slabs, sidx,
+                                       use_pallas=self.use_pallas)
+                        with leaf(spans.SYNC):
+                            dst_st.slabs.block_until_ready()
+                        with leaf(spans.G2G_SCATTER):
+                            dst_st.slabs = _scatter_into(
+                                dst_st.slabs, g, didx,
+                                use_pallas=self.use_pallas)
                     elif h.kind == "g2h":
                         win = self.ring_for(h.dst).window(wins[h.dst], nb)
-                        g = gather(src_st.slabs,
-                                   np.asarray(obj.rows[s:e], np.int32),
-                                   use_pallas=self.use_pallas)
-                        win[:] = np.asarray(g)     # d2h sync is the copy
+                        with leaf(spans.G2H_GATHER):
+                            g = gather(src_st.slabs,
+                                       np.asarray(obj.rows[s:e], np.int32),
+                                       use_pallas=self.use_pallas)
+                        with leaf(spans.G2H_D2H):
+                            got = np.asarray(g)    # d2h sync is the copy
+                        c["d2h"] += got.nbytes
+                        with leaf(spans.G2H_STAGE):
+                            win[:] = got
+                        c["stage"] += win.nbytes
                         cur = win
                         if h.dst == plan.dst:      # plan ends on a host
-                            dst_st.slabs[list(dst_rows[s:e])] = win
+                            with leaf(spans.G2H_WRITE):
+                                dst_st.slabs[list(dst_rows[s:e])] = win
+                            c["write"] += win.nbytes
                     elif h.kind in ("net", "h2h"):
                         dwin_key = hops[hi + 1].src \
                             if hi + 1 < len(hops) else None
                         if dwin_key is not None and dwin_key in wins:
                             dwin = self.ring_for(dwin_key).window(
                                 wins[dwin_key], nb)
-                            np.copyto(dwin, cur)
+                            with leaf(spans.NET_COPY):
+                                np.copyto(dwin, cur)
+                            c["stage"] += dwin.nbytes
                             cur = dwin
                         else:       # pure h2h plan: host store rows
                             src_rows = obj.rows[s:e]
-                            dst_st.slabs[list(dst_rows[s:e])] = \
-                                src_st.slabs[list(src_rows)]
+                            with leaf(spans.NET_COPY):
+                                dst_st.slabs[list(dst_rows[s:e])] = \
+                                    src_st.slabs[list(src_rows)]
+                            c["write"] += nb * SLAB_BYTES
                     elif h.kind == "h2g":
                         if cur is None:        # plan starts on a host:
                             # stage the batch through the src host's
                             # warm ring window, like pinned staging —
                             # gathered straight into the warm pages,
                             # no temp copy
-                            if h.src in wins:
-                                cur = self.ring_for(h.src).window(
-                                    wins[h.src], nb)
-                                _take_rows(src_st.slabs,
-                                           obj.rows[s:e], cur)
-                            else:
-                                cur = src_st.slabs[list(obj.rows[s:e])]
-                        up = jnp.asarray(np.ascontiguousarray(cur))
-                        dst_st.slabs.block_until_ready()
-                        dst_st.slabs = _scatter_into(
-                            dst_st.slabs, up,
-                            np.asarray(dst_rows[s:e], np.int32),
-                            use_pallas=self.use_pallas)
+                            with leaf(spans.H2G_STAGE):
+                                if h.src in wins:
+                                    cur = self.ring_for(h.src).window(
+                                        wins[h.src], nb)
+                                    _take_rows(src_st.slabs,
+                                               obj.rows[s:e], cur)
+                                else:
+                                    cur = src_st.slabs[
+                                        list(obj.rows[s:e])]
+                            c["stage"] += cur.nbytes
+                        with leaf(spans.H2G_H2D):
+                            up = jnp.asarray(np.ascontiguousarray(cur))
+                        c["h2d"] += cur.nbytes
+                        with leaf(spans.SYNC):
+                            dst_st.slabs.block_until_ready()
+                        with leaf(spans.H2G_SCATTER):
+                            dst_st.slabs = _scatter_into(
+                                dst_st.slabs, up,
+                                np.asarray(dst_rows[s:e], np.int32),
+                                use_pallas=self.use_pallas)
                     rep.hop_trace.append(tag)
                 # boundary sync: the batch is REALLY at the destination
                 if dst_st.device:
-                    dst_st.slabs.block_until_ready()
+                    with leaf(spans.SYNC):
+                        dst_st.slabs.block_until_ready()
                 landed(e, f"b{bi}:landed")
         finally:
             for hk, slots in wins.items():
@@ -516,6 +568,7 @@ class JaxBackend:
         n = len(obj.rows)
         cur_ep, cur_rows = plan.src, obj.rows
         inter: list[str] = []
+        c = self.counters
         for hi, h in enumerate(plan.hops):
             final = hi + 1 == len(plan.hops)
             dst_ep = plan.dst if final else \
@@ -530,39 +583,60 @@ class JaxBackend:
                 nxt_rows = dst_st.alloc(plan.data_id, obj.nbytes).rows
                 inter.append(dst_ep)
             for bi, (s, e) in enumerate(self._batches(n)):
+                nbytes = (e - s) * SLAB_BYTES
                 if src_st.device and dst_st.device:
-                    g = gather(src_st.slabs,
-                               np.asarray(cur_rows[s:e], np.int32),
-                               use_pallas=self.use_pallas)
-                    dst_st.slabs.block_until_ready()
-                    dst_st.slabs = _scatter_into(
-                        dst_st.slabs, g,
-                        np.asarray(nxt_rows[s:e], np.int32),
-                        use_pallas=self.use_pallas)
+                    with leaf(spans.G2G_GATHER):
+                        g = gather(src_st.slabs,
+                                   np.asarray(cur_rows[s:e], np.int32),
+                                   use_pallas=self.use_pallas)
+                    with leaf(spans.SYNC):
+                        dst_st.slabs.block_until_ready()
+                    with leaf(spans.G2G_SCATTER):
+                        dst_st.slabs = _scatter_into(
+                            dst_st.slabs, g,
+                            np.asarray(nxt_rows[s:e], np.int32),
+                            use_pallas=self.use_pallas)
                 elif src_st.device:
-                    out = dst_st.slabs[list(nxt_rows[s:e])]
-                    pool_to_host(src_st.slabs, list(cur_rows[s:e]), out,
-                                 batch=self.batch_chunks,
-                                 use_pallas=self.use_pallas)
-                    dst_st.slabs[list(nxt_rows[s:e])] = out
+                    with leaf(spans.G2H_STAGE):
+                        out = dst_st.slabs[list(nxt_rows[s:e])]
+                    c["stage"] += nbytes
+                    with leaf(spans.G2H_D2H):
+                        pool_to_host(src_st.slabs, list(cur_rows[s:e]),
+                                     out, batch=self.batch_chunks,
+                                     use_pallas=self.use_pallas)
+                    c["d2h"] += nbytes
+                    with leaf(spans.G2H_WRITE):
+                        dst_st.slabs[list(nxt_rows[s:e])] = out
+                    c["write"] += nbytes
                 elif dst_st.device:
-                    up = jnp.asarray(src_st.slabs[list(cur_rows[s:e])])
-                    dst_st.slabs.block_until_ready()
-                    dst_st.slabs = _scatter_into(
-                        dst_st.slabs, up,
-                        np.asarray(nxt_rows[s:e], np.int32),
-                        use_pallas=self.use_pallas)
+                    with leaf(spans.H2G_STAGE):
+                        rows = src_st.slabs[list(cur_rows[s:e])]
+                    c["stage"] += nbytes
+                    with leaf(spans.H2G_H2D):
+                        up = jnp.asarray(rows)
+                    c["h2d"] += nbytes
+                    with leaf(spans.SYNC):
+                        dst_st.slabs.block_until_ready()
+                    with leaf(spans.H2G_SCATTER):
+                        dst_st.slabs = _scatter_into(
+                            dst_st.slabs, up,
+                            np.asarray(nxt_rows[s:e], np.int32),
+                            use_pallas=self.use_pallas)
                 else:
-                    dst_st.slabs[list(nxt_rows[s:e])] = \
-                        src_st.slabs[list(cur_rows[s:e])]
+                    with leaf(spans.NET_COPY):
+                        dst_st.slabs[list(nxt_rows[s:e])] = \
+                            src_st.slabs[list(cur_rows[s:e])]
+                    c["write"] += nbytes
                 if final:
                     if dst_st.device:
-                        dst_st.slabs.block_until_ready()
+                        with leaf(spans.SYNC):
+                            dst_st.slabs.block_until_ready()
                     landed(e, f"h{hi}:b{bi}")
                 else:
                     rep.hop_trace.append(f"h{hi}:b{bi}")
             if dst_st.device:
-                dst_st.slabs.block_until_ready()
+                with leaf(spans.SYNC):
+                    dst_st.slabs.block_until_ready()
             # the whole object now sits at this hop's landing store
             rep.peak_staging_mb = max(
                 rep.peak_staging_mb,

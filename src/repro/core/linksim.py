@@ -128,6 +128,7 @@ from functools import partial
 from heapq import heappop, heappush
 
 from repro.core.pinned_buffer import FOREGROUND
+from repro.core.spans import span
 from repro.core.topology import Topology, PCIE_UNPINNED
 
 PIN_MS_PER_MB = 0.7
@@ -1956,10 +1957,11 @@ class LinkSim:
         events = self._events
         step = self.step
         n0 = self.n_events
-        while events:
-            if until is not None and events[0][0] > until:
-                break
-            step()
+        with span("sim.run"):
+            while events:
+                if until is not None and events[0][0] > until:
+                    break
+                step()
         TOTAL_EVENTS += self.n_events - n0
         return self.now
 

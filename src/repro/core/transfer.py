@@ -91,6 +91,7 @@ from repro.core.linksim import BATCH_CHUNKS, IPC_MS, LinkSim
 from repro.core.pathfinder import PathFinder
 from repro.core.pcie_scheduler import BACKGROUND, FOREGROUND
 from repro.core.pinned_buffer import CircularPinnedBuffer
+from repro.core.spans import span
 from repro.core.topology import Topology
 from repro.errors import TransferFailed
 
@@ -275,39 +276,40 @@ class TransferEngine:
                 slo_ms: float = 1e9, infer_ms: float = 0.0,
                 data_id: str = "") -> TransferPlan:
         """Map one movement kind onto its hop list, class and staging."""
-        assert kind in PLAN_KINDS, kind
-        par = self.h2g == "parallel"
-        hops: list[Hop] = []
-        if kind == "g2g":
-            if self.g2g == "host":
-                # staged through host memory; cut-through stitches the
-                # two PCIe legs, store-forward runs them sequentially
+        with span("plan"):
+            assert kind in PLAN_KINDS, kind
+            par = self.h2g == "parallel"
+            hops: list[Hop] = []
+            if kind == "g2g":
+                if self.g2g == "host":
+                    # staged through host memory; cut-through stitches the
+                    # two PCIe legs, store-forward runs them sequentially
+                    hs, hd = host_of(src), host_of(dst)
+                    hops = [Hop(src, hs, "g2h", staged=True),
+                            Hop(hd, dst, "h2g", staged=True)]
+                else:
+                    hops = [Hop(src, dst, "g2g",
+                                multipath=self.g2g == "multipath")]
+            elif kind == "internode":
                 hs, hd = host_of(src), host_of(dst)
                 hops = [Hop(src, hs, "g2h", staged=True),
+                        Hop(hs, hd, "net", routed=False),
                         Hop(hd, dst, "h2g", staged=True)]
-            else:
-                hops = [Hop(src, dst, "g2g",
-                            multipath=self.g2g == "multipath")]
-        elif kind == "internode":
-            hs, hd = host_of(src), host_of(dst)
-            hops = [Hop(src, hs, "g2h", staged=True),
-                    Hop(hs, hd, "net", routed=False),
-                    Hop(hd, dst, "h2g", staged=True)]
-        elif kind in ("h2g", "reload"):
-            hops = [Hop(src, dst, "h2g", multipath=par, staged=True)]
-        elif kind == "g2h":
-            hops = [Hop(src, host_of(src), "g2h", multipath=par,
-                        staged=True)]
-        elif kind == "spill":
-            hops = [Hop(src, dst, "g2h", staged=True)]
-        elif kind == "prefetch":
-            hops = [Hop(src, dst, "h2g", staged=True)]
-        elif kind == "h2h":
-            hops = [Hop(src, dst, "h2h")]
-        # ipc / shm: same address space, no hops
-        return TransferPlan(kind, func, src, dst, size_mb, hops, cls=cls,
-                            staging=self.staging, slo_ms=slo_ms,
-                            infer_ms=infer_ms, data_id=data_id)
+            elif kind in ("h2g", "reload"):
+                hops = [Hop(src, dst, "h2g", multipath=par, staged=True)]
+            elif kind == "g2h":
+                hops = [Hop(src, host_of(src), "g2h", multipath=par,
+                            staged=True)]
+            elif kind == "spill":
+                hops = [Hop(src, dst, "g2h", staged=True)]
+            elif kind == "prefetch":
+                hops = [Hop(src, dst, "h2g", staged=True)]
+            elif kind == "h2h":
+                hops = [Hop(src, dst, "h2h")]
+            # ipc / shm: same address space, no hops
+            return TransferPlan(kind, func, src, dst, size_mb, hops, cls=cls,
+                                staging=self.staging, slo_ms=slo_ms,
+                                infer_ms=infer_ms, data_id=data_id)
 
     # ------------------------------------------------------------ execute -
     def submit(self, plan: TransferPlan, t: float, on_done=None,
