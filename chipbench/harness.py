@@ -18,6 +18,14 @@ index over the first bytes of the entry (``reference.stamp``).  The
 check never reads the table: it recomputes every payload, stamp and all,
 from ``reference.py``.
 
+The check.  Every copy of a finished request's objects is digested
+while the clock is paused (``reference.digest``, 128 bits), on the chip
+(``Digester``): device rows are gathered and digested there, host rows
+are uploaded a chunk at a time, and only the digest comes back.  After
+the window each distinct payload is drawn and digested once, and each
+storing request's stamp is put into that digest by arithmetic
+(``reference.restamp``).
+
 Store sizes.  A store starts at ``SlabStore.START_MB`` and grows by its
 own rule when an allocation does not fit, which compiles a new pool
 shape.  Set-up first drives the cell's warm-up and two periods of its
@@ -45,18 +53,19 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from chipbench import reference, trace
+from chipbench import program_trace, reference, trace
 from chipbench.traffic import MB, DelayedConsumers, nbytes_of
 from repro.core import topology
 from repro.core.api import SYSTEMS, FaaSTube
-from repro.core.backend_jax import JaxBackend, SlabStore
-from repro.core.elastic_pool import BLOCK_MB, blocks_for
+from repro.core.backend_jax import SLAB_SHAPE, JaxBackend, SlabStore
+from repro.core.elastic_pool import BLOCK_MB, SLAB_BYTES, blocks_for
 from repro.core.linksim import BATCH_CHUNKS
 from repro.core.transfer import is_device
 
 BENCH_DIR = Path(__file__).resolve().parent
-#: rows the check reads back from a device pool per call
+#: slab rows the check digests per program call (one chunk)
 READ_ROWS = 8
+ROW_WORDS = SLAB_BYTES // 4
 #: events of a lowering to MLIR: one per compilation, cache hit or not
 COMPILE_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 PRESIZE_ID = "cb.presize"
@@ -205,10 +214,85 @@ def make_table(objects, workers: int) -> dict:
 
 @jax.jit
 def _read_rows(pool, idx):
-    """``pool[idx]`` for ``READ_ROWS`` indices, one dynamic slice each
-    (an XLA gather over the pool would stage a multiple of it)."""
-    return jnp.stack([lax.dynamic_index_in_dim(pool, idx[k], keepdims=False)
-                      for k in range(READ_ROWS)])
+    """``pool[idx]`` for ``READ_ROWS`` indices, one dynamic slice at a
+    time (an XLA gather over the pool would stage a multiple of it)."""
+    def put(k, out):
+        row = lax.dynamic_index_in_dim(pool, idx[k], keepdims=False)
+        return lax.dynamic_update_index_in_dim(out, row, k, 0)
+    return lax.fori_loop(0, READ_ROWS, put, jnp.zeros(
+        (READ_ROWS, *pool.shape[1:]), pool.dtype))
+
+
+@jax.jit
+def _digest_chunk(rows, start, nbytes):
+    """``reference.lane_sums`` of the bytes of ``rows`` (uint8
+    ``(READ_ROWS, *SLAB_SHAPE)``, row-major), which start at word
+    ``start`` of an object of ``nbytes``: bytes at or past ``nbytes`` are
+    left out.  Returns the ``LANES`` sums as uint32."""
+    n, r, c = rows.shape[0], rows.shape[1], rows.shape[2] // 4
+    w = lax.bitcast_convert_type(rows.reshape(n, r, c, 4), jnp.uint32)
+    pos = start + (lax.broadcasted_iota(jnp.int32, w.shape, 0) * (r * c)
+                   + lax.broadcasted_iota(jnp.int32, w.shape, 1) * c
+                   + lax.broadcasted_iota(jnp.int32, w.shape, 2))
+    inside = nbytes - 4 * pos             # bytes of the word in the object
+    w = jnp.where(inside >= 4, w,
+                  w & ((jnp.uint32(1) << (8 * jnp.clip(inside, 0, 3))
+                        .astype(jnp.uint32)) - 1))
+    p = pos.astype(jnp.uint32)
+    mix = reference.fmix32
+    return jnp.stack([
+        jnp.sum(jnp.where(inside > 0,
+                          mix(w ^ mix(p * jnp.uint32(reference.GOLDEN)
+                                      + jnp.uint32(key))),
+                          jnp.uint32(0)), dtype=jnp.uint32)
+        for key in reference.LANE_KEYS])
+
+
+class Digester:
+    """``reference.digest`` of slab-store copies and of payloads,
+    computed on the chip one chunk of ``READ_ROWS`` slab rows at a time:
+    only the digest comes back.  Rows of a device pool are gathered on
+    the chip (``_read_rows``); host rows and payloads are copied into one
+    staging buffer of a chunk and uploaded.  Each chunk's lane sums are
+    read back before the next chunk starts."""
+
+    def __init__(self):
+        self.stage = np.zeros((READ_ROWS, *SLAB_SHAPE), np.uint8)
+
+    def copy(self, store, data_id: str) -> tuple[int, ...]:
+        """The digest of the bytes a slab store holds for ``data_id``."""
+        obj = store.objects[data_id]
+        rows = np.asarray(obj.rows, np.int32)
+        out = [0] * reference.LANES
+        for s in range(0, len(rows), READ_ROWS):
+            part = rows[s:s + READ_ROWS]
+            if store.device:
+                idx = np.full(READ_ROWS, part[-1], np.int32)
+                idx[:len(part)] = part
+                chunk = _read_rows(store.slabs, idx)
+            else:
+                np.take(store.slabs, part, axis=0,
+                        out=self.stage[:len(part)])
+                chunk = jax.device_put(self.stage)
+            _add_chunk(out, chunk, s * ROW_WORDS, obj.nbytes)
+        return tuple(v & reference.MASK32 for v in out)
+
+    def payload(self, data: np.ndarray) -> tuple[int, ...]:
+        """The digest of a flat uint8 array."""
+        step = READ_ROWS * SLAB_BYTES
+        out = [0] * reference.LANES
+        for s in range(0, data.nbytes, step):
+            part = data[s:s + step]
+            self.stage.reshape(-1)[:part.nbytes] = part
+            _add_chunk(out, jax.device_put(self.stage), s // 4, data.nbytes)
+        return tuple(v & reference.MASK32 for v in out)
+
+
+def _add_chunk(out: list, rows, start: int, nbytes: int):
+    """Add the lane sums of one chunk (``_digest_chunk``) into ``out``."""
+    sums = np.asarray(_digest_chunk(rows, np.int32(start), np.int32(nbytes)))
+    for lane, v in enumerate(sums):
+        out[lane] += int(v)
 
 
 def read_copy(store, data_id: str) -> np.ndarray:
@@ -248,6 +332,7 @@ class Cell:
             self.backend = backend_cls(
                 table, store_mb=cfg["backend"]["store_mb"] * scale,
                 host_mb=cfg["backend"]["host_mb"] * scale)
+            self.digester = Digester()
         self.tube = FaaSTube(getattr(topology, cfg["topology"])(), tube_cfg,
                              backend=self.backend)
         self.r = 0
@@ -255,6 +340,7 @@ class Cell:
         self.calls: list[dict] = []
         self.finished = 0
         self.paused_s = 0.0
+        self.paused_bytes = 0
         self.compiles = 0
         self.snapshots: list[dict] = []
         self.store_peak_mb: dict[str, float] = {}
@@ -339,10 +425,11 @@ class Cell:
             if st.pool.capacity_mb < mb:
                 st.alloc(PRESIZE_ID, nbytes_of(mb - BLOCK_MB))
                 st.drop(PRESIZE_ID)
-        for ep in self.gen.devices:        # the check's reader, per pool
-            st = be.store_for(ep)
-            _read_rows(st.slabs, np.zeros(READ_ROWS, np.int32)) \
-                .block_until_ready()
+        zero = np.int32(0)
+        for ep in self.gen.devices:        # the check's programs, per pool
+            rows = _read_rows(be.store_for(ep).slabs,
+                              np.zeros(READ_ROWS, np.int32))
+            _digest_chunk(rows, zero, zero).block_until_ready()
 
     def warm_up(self):
         """Compile every program the window can run, then drive the
@@ -384,6 +471,7 @@ class Cell:
         be = self.backend
         self.calls, self.snapshots, self.store_peak_mb = [], [], {}
         self.finished, self.paused_s, self.compiles = 0, 0.0, 0
+        self.paused_bytes = 0
         self.first_report = len(be.reports)
         self.misses0 = be.misses
         self.sources0 = be.sources_missing
@@ -415,8 +503,8 @@ class Cell:
     def snapshot(self, req):
         """Read back every copy of a finished request's objects before
         they are consumed (the clock is paused meanwhile): a digest of
-        each copy, the plan kind that wrote it, and where the index and
-        the fetches say it should be."""
+        each copy, taken on the chip, the plan kind that wrote it, and
+        where the index and the fetches say it should be."""
         if not self.recording:
             return
         t0 = time.perf_counter()
@@ -426,11 +514,13 @@ class Cell:
                 rec = self.tube.index.global_table.get(o.data_id)
                 copies = {}
                 for ep in be.where(o.data_id):
-                    data = read_copy(be.stores[ep], o.data_id)
+                    store = be.stores[ep]
+                    nbytes = store.objects[o.data_id].nbytes
                     copies[ep] = {
                         "kind": be.delivered.get(o.data_id, {}).get(ep, "?"),
-                        "nbytes": int(data.nbytes),
-                        "digest": reference.digest(data)}
+                        "nbytes": int(nbytes),
+                        "digest": self.digester.copy(store, o.data_id)}
+                    self.paused_bytes += nbytes
                 self.snapshots.append({
                     "data_id": o.data_id, "nbytes": o.nbytes,
                     "request": req.index,
@@ -462,6 +552,25 @@ class Cell:
         }
 
 
+def reference_digests(want: dict, workers: int) -> dict:
+    """``{(data_id, nbytes): {request: digest}}`` for the requests in
+    ``want`` of each key: each payload drawn (in threads, ``workers`` at
+    a time) and digested once, each request's stamp then put in by
+    ``reference.restamp``."""
+    keys = list(want)
+    ref = {}
+    digester = Digester()
+    with ThreadPoolExecutor(max_workers=workers) as ex:
+        for s in range(0, len(keys), workers):
+            batch = keys[s:s + workers]
+            for k, data in zip(batch, ex.map(
+                    lambda k: reference.payload(*k), batch)):
+                d = digester.payload(data)
+                head = data[:reference.STAMP_BYTES].copy()
+                ref[k] = {r: reference.restamp(d, head, r) for r in want[k]}
+    return ref
+
+
 def check(snapshots: list, workers: int) -> dict:
     """Compare every copy read back with the reference: its bytes, that
     each fetch's destination holds one (or a spill has since moved it
@@ -470,10 +579,7 @@ def check(snapshots: list, workers: int) -> dict:
     want: dict[tuple, set] = {}
     for s in snapshots:
         want.setdefault((s["data_id"], s["nbytes"]), set()).add(s["request"])
-    with ThreadPoolExecutor(max_workers=workers) as ex:
-        futs = {k: ex.submit(reference.stamped_digests, *k, reqs)
-                for k, reqs in want.items()}
-        ref = {k: f.result() for k, f in futs.items()}
+    ref = reference_digests(want, workers)
     wrong = missing = index_wrong = checked = 0
     by_kind: dict[str, int] = {}
     for s in snapshots:
@@ -567,20 +673,26 @@ def run_cell(spec: dict, seed: int, seconds: float, traced: bool, *,
     cell = build(spec, seed, scale=scale, backend_cls=backend_cls,
                  workers=workers)
     setup_s = time.perf_counter() - t_start
-    red = None
+    rec_trace = {"trace": None}
     if traced:
+        before = dict(getattr(cell.backend, "counters", {}))
         tdir = tempfile.mkdtemp(prefix="chipbench-trace-")
         try:
             with jax.profiler.trace(tdir):
                 cell.window(seconds)
-            red = trace.reduce(trace.extract(trace.find_xplane(tdir)))
+            ev = program_trace.extract(trace.find_xplane(tdir))
         finally:
             shutil.rmtree(tdir, ignore_errors=True)
+        rec_trace = {
+            "trace": program_trace.reduce(ev),
+            "spans": program_trace.spans(ev),
+            "counters": program_trace.counter_delta(before, cell.backend)}
     else:
         cell.window(seconds)
     mem = memory_peak_bytes()
     rec = cell.record()
-    rec.update(setup_s=setup_s, trace=red, peaks=peaks)
+    rec.update(setup_s=setup_s, peaks=peaks, **rec_trace)
+    red = rec["trace"]
     window = cell.window_counts()
     log(f"chipbench: window {rec['window_s']:.3f}s, "
         f"{sum(c['op'] == 'fetch' for c in rec['calls'])} fetch calls, "
@@ -591,18 +703,21 @@ def run_cell(spec: dict, seed: int, seconds: float, traced: bool, *,
         f"{window['window_compiles']}, payload-table misses "
         f"{window['table_misses']}, sources missing "
         f"{window['sources_missing']}")
+    snapshots, paused_s, paused_bytes = \
+        cell.snapshots, cell.paused_s, cell.paused_bytes
+    del cell                  # free the program's state before the check
+    gc.collect()
+    t0 = time.perf_counter()
+    counts = check(snapshots, workers)
+    check_s = time.perf_counter() - t0
+    checks = limits(counts, window)
     log("chipbench: plans in window "
         + json.dumps(rec["plan_counts"], sort_keys=True)
         + "; store live peak MB " + json.dumps(rec["store_peak_mb"],
                                                sort_keys=True)
         + "; store pool MB " + json.dumps(rec["pool_mb"], sort_keys=True)
-        + f"; memory_peak_bytes {mem}; read-back pause "
-        f"{cell.paused_s:.3f}s")
-    snapshots = cell.snapshots
-    del cell                  # free the program's state before the check
-    gc.collect()
-    counts = check(snapshots, workers)
-    checks = limits(counts, window)
+        + f"; memory_peak_bytes {mem}; read-back pause {paused_s:.3f}s, "
+        f"{paused_bytes / 1e9:.3f} GB digested; check {check_s:.3f}s")
     log(f"chipbench: copies checked by plan kind {counts['by_kind']}")
     metrics = {}
     for m in spec["per_layer" if traced else "end_to_end"]:

@@ -7,41 +7,23 @@ The data plane annotates its work with ``ft.`` host spans
 
 - ``extract`` is ``trace.extract`` plus ``"program"``: every ``ft.``
   host span as ``[name, start_ns, dur_ns, thread]``;
-- ``spans`` gives, inside the active windows, each ``ft.`` name's
-  inclusive seconds, self seconds (less the ``ft.`` spans nested in it
-  on the same thread) and count; it needs no device plane;
+- ``spans`` gives, inside the measured window (``trace.windows``),
+  each ``ft.`` name's inclusive seconds, self seconds (less the ``ft.``
+  spans nested in it on the same thread) and count;
 - ``reduce`` is ``trace.reduce`` with each idle gap charged to the
   innermost span of either family.
 
-Run one cell traced, with the program's spans read into the record and
-the readers of ``PER_LAYER`` applied to it (copies are not read back,
-so there is no ``correct``: ``chipbench.run`` checks them):
-
-    python3 -m chipbench.program_trace --workload <cell> --seed <n> \\
-        --seconds <s>
-
-Its last line is one JSON object: ``metrics`` (the cell's end-to-end
-metrics and every per-layer metric of the cell and of ``PER_LAYER``),
-``breakdown`` (by ``reduce``), ``spans`` and ``counters``.
+A traced ``chipbench.run`` reads them into its record
+(``harness.run_cell``), where the readers under ``metrics/`` find them.
 """
 from __future__ import annotations
 
-import argparse
-import json
-import shutil
-import sys
-import tempfile
-import time
 from bisect import bisect_right
 
 from chipbench import trace
 
-T_START = time.perf_counter()
 #: the program's span prefix (``repro.core.spans.PREFIX``)
 PREFIX = "ft."
-#: readers of the program's spans and counters (``metrics/<name>.py``)
-PER_LAYER = ("facade_self_ms_per_call", "put_dev_GBps", "hostcopy_GBps",
-             "link_wait_share")
 
 
 def extract(xplane_path: str) -> dict:
@@ -78,10 +60,10 @@ def _overlap(s, e, windows, starts) -> float:
 
 def spans(ev: dict) -> dict:
     """``{name: {"incl_s", "self_s", "count"}}`` of every ``ft.`` span:
-    its seconds inside the active windows, those seconds less the
+    its seconds inside the measured window, those seconds less the
     ``ft.`` spans nested in it on the same thread, and how many start
-    inside them."""
-    windows = trace.active_windows(ev["host"])
+    inside it."""
+    windows = trace.windows(ev)
     starts = [w[0] for w in windows]
     threads: dict = {}
     for h in ev.get("program", ()):
@@ -113,74 +95,3 @@ def counter_delta(before: dict, backend) -> dict:
     for a program that keeps no counters."""
     return {k: v - before.get(k, 0)
             for k, v in getattr(backend, "counters", {}).items()}
-
-
-def run(spec: dict, seed: int, seconds: float, *, peaks: dict | None,
-        t_start: float, scale: float = 1.0, workers: int = 8,
-        log=print) -> dict:
-    """Set up the cell, trace one window, and read it."""
-    import jax
-    from chipbench import harness
-    cell = harness.build(spec, seed, scale=scale, workers=workers)
-    cell.snapshot = lambda req: None          # no read-back pauses
-    setup_s = time.perf_counter() - t_start
-    before = dict(getattr(cell.backend, "counters", {}))
-    tdir = tempfile.mkdtemp(prefix="chipbench-trace-")
-    try:
-        with jax.profiler.trace(tdir):
-            cell.window(seconds)
-        ev = extract(trace.find_xplane(tdir))
-    finally:
-        shutil.rmtree(tdir, ignore_errors=True)
-    rec = cell.record()
-    red = reduce(ev)
-    rec.update(setup_s=setup_s, trace=red, peaks=peaks, spans=spans(ev),
-               counters=counter_delta(before, cell.backend))
-    log(f"chipbench: window {rec['window_s']:.3f}s, "
-        f"{len(ev['program'])} program spans")
-    names = [m["name"] for m in spec["end_to_end"] + spec["per_layer"]]
-    names += [n for n in PER_LAYER if n not in names]
-    metrics = {}
-    for name in names:
-        val = harness.load_reader(name)(rec)
-        if val is not None:
-            metrics[name] = val
-    idle = red["idle_s"] if red else {}
-    total = sum(idle.values())
-    return {"metrics": metrics,
-            "breakdown": trace.breakdown(red, top=20) if red else None,
-            "idle_ft_share": (sum(v for k, v in idle.items()
-                                  if k.startswith(PREFIX)) / total
-                              if total else None),
-            "window_s": red["window_s"] if red else None,
-            "busy_s": red["busy_s"] if red else None,
-            "spans": rec["spans"], "counters": rec["counters"]}
-
-
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(prog="chipbench.program_trace")
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    args = ap.parse_args(argv)
-    from chipbench import run as bench
-    sys.path.insert(0, str(bench.ROOT / "src"))
-    from chipbench import harness
-    spec = harness.load_spec(args.workload, bench.ROOT)
-    import jax
-    d = jax.devices()[0]
-    if d.platform != "tpu":
-        sys.exit(f"chipbench: no TPU: JAX found {d.platform!r}")
-    print(f"chipbench: compile cache {bench.compile_cache(bench.ROOT)}",
-          flush=True)
-    out = run(spec, args.seed, args.seconds,
-              peaks=bench.load_peaks(d.device_kind), t_start=T_START,
-              workers=harness.default_workers(),
-              log=lambda s: print(s, flush=True))
-    out["device"] = d.device_kind
-    print(json.dumps(out), flush=True)
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

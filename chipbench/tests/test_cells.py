@@ -4,7 +4,7 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import harness, run
+from chipbench import harness, program_trace, run
 
 ROOT = Path(__file__).resolve().parents[2]
 SCALE = 1 / 32
@@ -76,6 +76,34 @@ def test_traced_body_reports_per_layer_metrics():
     assert set(out["metrics"]) == names - {"copy_roofline", "device_idle"}
     assert out["metrics"]["spill_MB_per_req"]["value"] > 0
     assert out["metrics"]["reload_MB_per_req"]["value"] > 0
+
+
+PROGRAM_METRICS = ("facade_self_ms_per_call", "put_dev_GBps",
+                   "hostcopy_GBps", "link_wait_share")
+
+
+def test_traced_run_reads_the_program_spans(monkeypatch):
+    """table1.media at 1/32, traced: the program's four metrics are in
+    the line, and the breakdown charges idle gaps to ``ft.`` leaves.  The
+    CPU trace has no device plane, so one is put in: an op at each edge
+    of every program span, which leaves the gaps inside the spans."""
+    extract = program_trace.extract
+
+    def with_device(path):
+        ev = extract(path)
+        edges = {t for _, s, d, _ in ev["program"] for t in (s, s + d)}
+        ev["device"] = {"/device:TPU:0": [["XLA Ops", "op", t, 1]
+                                          for t in sorted(edges)]}
+        return ev
+    monkeypatch.setattr(program_trace, "extract", with_device)
+    spec, out, _ = _run("table1.media", traced=True)
+    assert out["correct"], out["checks"]
+    assert {m["name"] for m in spec["per_layer"]} >= set(PROGRAM_METRICS)
+    assert set(PROGRAM_METRICS) <= set(out["metrics"])
+    assert 0 < out["metrics"]["link_wait_share"]["value"] <= 100
+    assert 0 < out["busy_s"] < out["window_s"]
+    gaps = [name for name, _ in out["breakdown"]["idle_gaps"]]
+    assert gaps and gaps[0].startswith("ft."), gaps
 
 
 def test_main_refuses_cpu(capsys):

@@ -1,7 +1,7 @@
 """The program's spans and counters as ``chipbench.program_trace`` reads
 them: the reduction with both span families on recorded chip traces and
-hand-made ones, the readers of ``PER_LAYER`` on a hand-made record, and
-one traced cell at 1/32 of its size on the CPU."""
+hand-made ones, and the readers of ``PER_LAYER`` on a hand-made record
+(``test_cells`` runs a traced cell through them)."""
 import json
 from pathlib import Path
 
@@ -10,9 +10,11 @@ import pytest
 from chipbench import harness, program_trace, trace
 from repro.core import spans
 
-ROOT = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).resolve().parent / "data"
 MB = 2 ** 20
+#: readers of the program's spans and counters (``metrics/<name>.py``)
+PER_LAYER = ("facade_self_ms_per_call", "put_dev_GBps", "hostcopy_GBps",
+             "link_wait_share")
 
 #: program spans of a traced window: inclusive and self seconds, count
 SPANS = {
@@ -173,42 +175,26 @@ def _record(trace=True):
             "spans": SPANS if trace else None, "counters": COUNTERS}
 
 
-@pytest.mark.parametrize("metric", program_trace.PER_LAYER)
+@pytest.mark.parametrize("metric", PER_LAYER)
 def test_reader(metric):
     got = harness.load_reader(metric)(_record())
     assert got == pytest.approx(EXPECTED[metric], rel=1e-9)
 
 
-@pytest.mark.parametrize("metric", program_trace.PER_LAYER)
+@pytest.mark.parametrize("metric", PER_LAYER)
 def test_reader_is_silent_without_a_trace(metric):
     assert harness.load_reader(metric)(_record(trace=False)) is None
 
 
-@pytest.mark.parametrize("metric", program_trace.PER_LAYER)
+@pytest.mark.parametrize("metric", PER_LAYER)
 def test_reader_is_silent_without_program_spans(metric):
     """A traced program that has no ``ft.`` spans or counters."""
     rec = dict(_record(), spans={}, counters={})
     assert harness.load_reader(metric)(rec) is None
 
 
-@pytest.mark.parametrize("metric", program_trace.PER_LAYER)
+@pytest.mark.parametrize("metric", PER_LAYER)
 def test_reader_is_silent_on_a_benchmark_record(metric):
-    """The record ``harness.run_cell`` makes holds no spans or counters."""
+    """The record of an untraced ``harness.run_cell`` holds no spans or
+    counters."""
     assert harness.load_reader(metric)({"trace": {}}) is None
-
-
-def test_traced_cell_reads_the_program_metrics():
-    """memstress_b2.held at 1/32 traced on the CPU: every reader of
-    ``PER_LAYER`` reads a number (the CPU trace has no device plane, so
-    there is no breakdown)."""
-    spec = harness.load_spec("memstress_b2.held", ROOT)
-    lines = []
-    out = program_trace.run(spec, 2 ** 31 + 12345, 1.0, peaks=None,
-                            t_start=0.0, scale=1 / 32, workers=2,
-                            log=lines.append)
-    assert set(program_trace.PER_LAYER) <= set(out["metrics"])
-    assert out["metrics"]["link_wait_share"] <= 100.0
-    assert out["breakdown"] is None
-    assert out["counters"]["put.dev"] > 0
-    assert out["spans"]["ft.store"]["count"] > 0
-    assert any(line.startswith("chipbench: window") for line in lines)

@@ -59,3 +59,19 @@ def test_pause_is_left_out_of_the_window(ev):
 
 def test_no_device_events_reads_nothing(ev):
     assert trace.reduce(dict(ev, device={})) is None
+
+
+def test_own_programs_are_left_out_of_the_window(ev):
+    """A run of the check's digest program that the device clock puts
+    just inside the window counts neither as busy nor as window."""
+    (w0, wd), = [(s, d) for n, s, d in ev["host"] if n == "cb.window"]
+    plane = next(iter(ev["device"]))
+    s, d = w0 + wd - 5000, 4000
+    mine = [["XLA Modules", "jit__digest_chunk(12345)", s, d],
+            ["XLA Ops", "fusion.1", s + 100, d - 200]]
+    dev = dict(ev["device"], **{plane: ev["device"][plane] + mine})
+    red, full = trace.reduce(dict(ev, device=dev)), trace.reduce(ev)
+    assert red["window_s"] == pytest.approx(full["window_s"] - d * 1e-9,
+                                            rel=1e-12)
+    assert red["busy_s"] == pytest.approx(full["busy_s"], rel=1e-12)
+    assert "jit__digest_chunk" not in red["module_s"]

@@ -8,7 +8,9 @@ start with ``cb.``).  ``reduce`` works on that and nothing else, so it
 can be checked on a small recorded trace.
 
 The measured window is the ``cb.window`` span less its ``cb.pause``
-spans (where the check reads copies back).  Device busy time is the
+spans (where the check reads copies back) and less the runs of the
+check's own device programs (``OWN_PROGRAMS``), which the device clock
+can place a little outside the pause.  Device busy time is the
 union of the op intervals of each device plane's ``XLA Ops`` and
 ``Async XLA Ops`` lines inside that window; program time is read from
 its ``XLA Modules`` line.
@@ -26,6 +28,8 @@ OPS_LINES = ("XLA Ops", "Async XLA Ops")
 #: one event per program run, named ``<jit name>(<fingerprint>)``
 MODULES_LINE = "XLA Modules"
 _SUFFIX = re.compile(r"\(\d+\)$")
+#: the benchmark's own device programs: the check's read-back
+OWN_PROGRAMS = ("jit__read_rows", "jit__digest_chunk")
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -91,14 +95,13 @@ def _length(intervals) -> float:
     return float(sum(e - s for s, e in intervals))
 
 
-def active_windows(host) -> list:
-    """The ``cb.window`` spans less the ``cb.pause`` spans, in ns."""
-    wins = _union([[s, s + d] for n, s, d in host if n == "cb.window"])
-    pauses = _union([[s, s + d] for n, s, d in host if n == "cb.pause"])
+def _subtract(intervals, cuts) -> list:
+    """Sorted disjoint ``intervals`` less the union of ``cuts``."""
+    cuts = _union(cuts)
     out = []
-    for s, e in wins:
+    for s, e in intervals:
         cur = s
-        for ps, pe in pauses:
+        for ps, pe in cuts:
             if pe <= cur or ps >= e:
                 continue
             if ps > cur:
@@ -107,6 +110,18 @@ def active_windows(host) -> list:
         if cur < e:
             out.append([cur, e])
     return out
+
+
+def windows(ev: dict) -> list:
+    """The measured window, in ns: the ``cb.window`` spans less the
+    ``cb.pause`` spans and the runs of ``OWN_PROGRAMS``."""
+    host = ev["host"]
+    return _subtract(
+        _union([[s, s + d] for n, s, d in host if n == "cb.window"]),
+        [[s, s + d] for n, s, d in host if n == "cb.pause"]
+        + [[s, s + d] for evs in ev["device"].values()
+           for line, n, s, d in evs
+           if line == MODULES_LINE and _SUFFIX.sub("", n) in OWN_PROGRAMS])
 
 
 def _attribute(spans, gaps) -> dict:
@@ -132,9 +147,9 @@ def reduce(ev: dict) -> dict | None:
     """Busy and window seconds, seconds per program, and idle seconds by
     the host span they fall in; None when the trace holds no device op
     inside the window."""
-    windows = active_windows(ev["host"])
-    window_ns = _length(windows)
-    if not windows or not ev["device"]:
+    win = windows(ev)
+    window_ns = _length(win)
+    if not win or not ev["device"]:
         return None
     busy_ns, module_ns, idle = 0.0, {}, {}
     spans = [h for h in ev["host"]
@@ -142,16 +157,16 @@ def reduce(ev: dict) -> dict | None:
     for evs in ev["device"].values():
         for line, n, s, d in evs:
             if line == MODULES_LINE:
-                part = _length(_clip([[s, s + d]], windows))
+                part = _length(_clip([[s, s + d]], win))
                 if part > 0:
                     name = _SUFFIX.sub("", n)
                     module_ns[name] = module_ns.get(name, 0.0) + part
         busy = _clip(_union([[s, s + d] for line, n, s, d in evs
-                             if line in OPS_LINES]), windows)
+                             if line in OPS_LINES]), win)
         busy_ns += _length(busy)
         # idle gaps: the window less the busy intervals
         gaps = []
-        for ws, we in windows:
+        for ws, we in win:
             cur = ws
             for bs, be in busy:
                 if be <= ws or bs >= we:
