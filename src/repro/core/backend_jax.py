@@ -64,9 +64,10 @@ MB = 2 ** 20
 #: the shape so every device<->host copy is a plain memcpy.
 SLAB_SHAPE = (SLAB_BYTES // 1024, 1024)
 #: ``JaxBackend.counters``: bytes through each host-side step — object
-#: writes into device and host stores, the padded copy of a put, host
-#: copies into the ring or staged rows, writes of host destination rows,
-#: uploads and downloads
+#: writes into device and host stores, the row image a put hands on
+#: (``pad``: whole slab rows, a view of the payload when it fills whole
+#: rows), host copies into the ring or staged rows, writes of host
+#: destination rows, uploads and downloads
 COUNTERS = ("put.dev", "put.host", "pad", "stage", "write", "h2d", "d2h")
 
 
@@ -127,6 +128,9 @@ class SlabStore:
             self.slabs = np.zeros((self.pool.n_slabs, *SLAB_SHAPE),
                                   np.uint8)
         self.objects: dict[str, _Obj] = {}
+        #: warm row image of ragged device puts, grown to the most rows
+        #: one has needed (``_warm_image``)
+        self.image = np.empty((0, *SLAB_SHAPE), np.uint8)
 
     def __contains__(self, data_id: str) -> bool:
         return data_id in self.objects
@@ -169,19 +173,33 @@ class SlabStore:
         return obj
 
     def put(self, data_id: str, payload: np.ndarray) -> _Obj:
-        """Materialize host bytes into the store (the write path)."""
+        """Materialize host bytes into the store (the write path).
+
+        The row image is ``(rows, *SLAB_SHAPE)``, zero past the payload.
+        A payload that fills whole rows is its own image, a view read in
+        place.  A ragged one is copied into the store's warm image on a
+        device (``_warm_image``); on a host its whole rows, a view, and
+        its tail are written apart (``_put_rows``).  The scatter is
+        waited for before returning, so the caller may then change
+        ``payload`` and the next put may overwrite the warm image."""
         c = self.counters
         with leaf(spans.PUT_DEV if self.device else spans.PUT_HOST):
             payload = np.ascontiguousarray(payload, dtype=np.uint8).ravel()
             obj = self.alloc(data_id, payload.nbytes)
+            n = len(obj.rows)
+            whole = payload.nbytes // SLAB_BYTES
             with leaf(spans.PUT_PAD):
-                chunks = _chunk_rows(payload)
-            c["pad"] += chunks.nbytes
+                if self.device and whole < n:
+                    image = self._warm_image(payload, n)
+                else:
+                    image = payload[:whole * SLAB_BYTES].reshape(
+                        whole, *SLAB_SHAPE)
+            c["pad"] += n * SLAB_BYTES
             if self.device:
                 idx = np.asarray(obj.rows, np.int32)
                 with leaf(spans.PUT_H2D):
-                    up = jnp.asarray(chunks)
-                c["h2d"] += chunks.nbytes
+                    up = jnp.asarray(image)
+                c["h2d"] += image.nbytes
                 with leaf(spans.PUT_SCATTER):
                     self.slabs = _scatter_into(self.slabs, up, idx,
                                                use_pallas=False)
@@ -190,10 +208,24 @@ class SlabStore:
                 c["put.dev"] += payload.nbytes
             else:
                 with leaf(spans.PUT_WRITE):
-                    self.slabs[list(obj.rows)] = chunks
-                c["write"] += chunks.nbytes
+                    _put_rows(self.slabs, obj.rows, image,
+                              payload[whole * SLAB_BYTES:])
+                c["write"] += n * SLAB_BYTES
                 c["put.host"] += payload.nbytes
         return obj
+
+    def _warm_image(self, payload: np.ndarray, n: int) -> np.ndarray:
+        """The first ``n`` rows of the warm image, holding ``payload``
+        and zero after it.  The image grows only when a put needs more
+        rows than it has; only the tail of row ``n`` is zeroed, since
+        the payload overwrites every byte before it."""
+        with leaf(spans.PUT_TAIL):
+            if len(self.image) < n:
+                self.image = np.empty((n, *SLAB_SHAPE), np.uint8)
+            flat = self.image[:n].reshape(-1)
+            flat[:payload.nbytes] = payload
+            flat[payload.nbytes:] = 0
+        return self.image[:n]
 
     def read(self, data_id: str) -> np.ndarray:
         """Materialize an object back to host bytes (verification path,
@@ -217,26 +249,35 @@ class SlabStore:
         return self.pool.used_mb
 
 
+def _is_run(rows) -> bool:
+    """Whether ``rows`` are one ascending contiguous run.  Fresh
+    allocations hand out sequential slab rows, so that is the common
+    case, and a slice copies it ~2x faster than ``np.take``/fancy
+    indexing for trigger-batch-sized copies."""
+    return all(rows[i] == rows[0] + i for i in range(1, len(rows)))
+
+
 def _take_rows(pool: np.ndarray, rows, out: np.ndarray):
-    """Copy ``pool[rows]`` into ``out``.  Fresh allocations hand out
-    sequential slab rows, so the common case is a contiguous run — a
-    straight memcpy slice, ~2x faster than ``np.take``/fancy indexing
-    for trigger-batch-sized copies."""
-    r0 = rows[0]
-    n = len(rows)
-    if all(rows[i] == r0 + i for i in range(1, n)):
-        out[:] = pool[r0:r0 + n]
+    """Copy ``pool[rows]`` into ``out``."""
+    if _is_run(rows):
+        out[:] = pool[rows[0]:rows[0] + len(rows)]
     else:
         out[:] = pool[list(rows)]
 
 
-def _chunk_rows(payload: np.ndarray) -> np.ndarray:
-    """Reshape flat bytes to (rows, *SLAB_SHAPE), zero-padding the
-    tail."""
-    rows = -(-payload.nbytes // SLAB_BYTES)
-    out = np.zeros((rows, *SLAB_SHAPE), np.uint8)
-    out.reshape(-1)[:payload.nbytes] = payload
-    return out
+def _put_rows(pool: np.ndarray, rows, body: np.ndarray, tail: np.ndarray):
+    """Write ``body`` (whole rows) into the first ``len(body)`` of
+    ``pool[rows]``, then ``tail`` (under one row of flat bytes) into the
+    last row, zeroing the rest of that row."""
+    n = len(body)
+    if _is_run(rows):
+        pool[rows[0]:rows[0] + n] = body
+    else:
+        pool[list(rows[:n])] = body
+    if len(rows) > n:
+        last = pool[rows[-1]].reshape(-1)
+        last[:tail.size] = tail
+        last[tail.size:] = 0
 
 
 class HostRing:

@@ -20,11 +20,16 @@ PREFIX = "ft."
 #: one object write into a slab store, device or host, and its parts
 PUT_DEV = PREFIX + "put.dev"
 PUT_HOST = PREFIX + "put.host"
-PUT_PAD = PREFIX + "put.pad"          # the zero-padded copy (_chunk_rows)
-PUT_H2D = PREFIX + "put.h2d"          # the upload of the padded array
+#: the row image a put hands on: a view of the payload's whole rows,
+#: or for a ragged device payload a copy into the store's warm image
+PUT_PAD = PREFIX + "put.pad"
+#: that copy, inside ``PUT_PAD``: its count over ``PUT_DEV``'s is the
+#: share of device puts that still copy
+PUT_TAIL = PREFIX + "put.tail"
+PUT_H2D = PREFIX + "put.h2d"          # the upload of the row image
 PUT_SCATTER = PREFIX + "put.scatter"  # dispatch of the whole-object scatter
 PUT_SYNC = PREFIX + "put.sync"        # wait for that scatter
-PUT_WRITE = PREFIX + "put.write"      # a host store's row assignment
+PUT_WRITE = PREFIX + "put.write"      # a host store's rows and tail
 GROW = PREFIX + "grow"                # one slab pool growth
 #: one trigger batch of one hop
 H2G_STAGE = PREFIX + "h2g.stage"      # host rows into the ring window
